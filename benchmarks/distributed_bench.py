@@ -14,7 +14,10 @@ ways — multi-host simulated via a local host-platform device mesh:
 The simulated mesh needs its own process (jax locks the device count at
 first init), so ``run()`` re-executes this module in a subprocess with
 ``--xla_force_host_platform_device_count`` and collects the entries via
-``--emit``. ``run(json_path=...)`` writes BENCH_distributed.json, the
+``--emit``. That child is pinned to ``JAX_PLATFORMS=cpu``: it never
+measures the chip, and its timings are host interpret-mode numbers, not
+device metrics (``chip_smoke.py --chips 4`` is the on-chip check of the
+same loss). ``run(json_path=...)`` writes BENCH_distributed.json, the
 committed perf trajectory gated by scripts/check_bench.py through
 ``benchmarks/run.py --json`` exactly like the kernel and serving benches.
 """
@@ -41,6 +44,7 @@ def _bench_entries() -> dict:
 
     from repro.core import distributed_loss as dl
     from repro.core.contrastive import fused_kernel_loss
+    from repro.launch.mesh import make_mesh
 
     assert jax.device_count() >= R, jax.devices()
     interpret = jax.default_backend() == "cpu"
@@ -56,7 +60,7 @@ def _bench_entries() -> dict:
         def ref_loss(x, y, tau):
             return fused_kernel_loss(x, y, tau, interpret=interpret)[0]
 
-        mesh = jax.make_mesh((R,), ("data",))
+        mesh = make_mesh((R,), ("data",))
         fns = {"dist_ref": jax.jit(jax.value_and_grad(
             ref_loss, argnums=(0, 1, 2)))}
         for method in dl.METHODS:

@@ -78,9 +78,8 @@ def run(json_path: str | None = None, shapes=None) -> dict:
         # backward when the dY carrier won't fit VMEM (DESIGN.md §2.3);
         # record the launch count so a fused2 entry that actually measured
         # the fallback (3 launches) is visible in the committed trajectory.
-        bm, bn = pick_blocks(b, d, 4)
-        fused_launches = 2 if (interpret or ops.bwd_fits_fused(
-            b, d, bm, bn, 4)) else 3
+        fused_launches = 2 if ops.backward_sweep(
+            b, d, 4, interpret=interpret) == "fused" else 3
         for name, (fwd, fwdbwd) in _paths(b, d, interpret).items():
             for tag, fn in (("fwd", fwd), ("fwdbwd", fwdbwd)):
                 us = _timeit(fn, x, y, log_tau, iters=iters)

@@ -199,7 +199,12 @@ def _sharded_entries_body() -> dict:
 def _sharded_entries(entries: dict) -> None:
     """Spawn the simulated-mesh subprocess (same pattern as
     benchmarks/distributed_bench.py: jax locks the device count at first
-    init, so the parent process cannot host the mesh itself)."""
+    init, so the parent process cannot host the mesh itself).
+
+    CPU only: the child is pinned to ``JAX_PLATFORMS=cpu`` and starts after
+    this process has imported JAX, so it never measures the chip (a parent
+    that holds the chip would starve it). Its numbers are host interpret
+    timings, not device metrics."""
     with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as f:
         emit = f.name
     try:

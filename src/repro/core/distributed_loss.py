@@ -36,7 +36,7 @@ drop-in for ``core.gradaccum.contrastive_step``, so Algorithm-1 gradient
 accumulation, data parallelism, and tensor-parallel towers compose under
 one jit (launch/train_distributed.py --objective contrastive).
 
-shard_map runs with ``check_rep=False`` (Pallas calls have no replication
+shard_map runs with ``check_vma=False`` (Pallas calls have no replication
 rule), which fixes the AD boundary convention this module compensates
 for: the cotangent of the replicated P() loss arrives at each shard
 scaled by 1/R, per-shard cotangents returned for P(data) inputs are used
@@ -51,7 +51,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import sharding as shd
@@ -195,7 +194,7 @@ def _chunked_bwd(axis, interpret, bm, bn, res, g):
     # psum-scatter sums across shards and hands each shard its own block
     dy = jax.lax.psum_scatter(dy_parts.reshape(b_g, d), axis, tiled=True)
 
-    # check_rep=False boundary compensation (module docstring): the
+    # check_vma=False boundary compensation (module docstring): the
     # incoming replicated-loss cotangent g is scaled 1/R per shard, and
     # the replicated log_tau's cotangent is psum'd by the unmapping — so
     # scale everything by R and return the LOCAL dτ contribution unpsum'd
@@ -256,9 +255,9 @@ def make_global_loss_fn(mesh, method: str = "chunked", *, data_axes=None,
                                    interpret=interpret, bm=bm, bn=bn)
         return chunked_loss(x_l, y_l, log_tau, axis, interpret, bm, bn)
 
-    mapped = shard_map(local_fn, mesh=mesh,
-                       in_specs=(P(data_axes), P(data_axes), P()),
-                       out_specs=P(), check_rep=False)
+    mapped = jax.shard_map(local_fn, mesh=mesh,
+                           in_specs=(P(data_axes), P(data_axes), P()),
+                           out_specs=P(), check_vma=False)
 
     def loss_fn(x, y, tau):
         loss = mapped(x, y, jnp.log(tau))

@@ -28,9 +28,20 @@ from repro.core.contrastive import contrastive_loss
 
 
 def _split(tree, k):
-    """Reshape every leaf (B, ...) -> (k, B//k, ...)."""
-    return jax.tree.map(lambda x: x.reshape(k, x.shape[0] // k, *x.shape[1:]),
-                        tree)
+    """Reshape every leaf (B, ...) -> (k, B//k, ...), microbatch j taking
+    rows j, j+k, j+2k, ... . A batch sharded in contiguous per-device
+    blocks then gives every microbatch an equal share of every device's
+    rows, so the microbatch scan stays data-parallel (a contiguous split
+    would put whole microbatches on single devices)."""
+    return jax.tree.map(
+        lambda x: jnp.swapaxes(
+            x.reshape(x.shape[0] // k, k, *x.shape[1:]), 0, 1), tree)
+
+
+def _merge(z):
+    """Inverse of ``_split`` for a stacked (k, B//k, D) leaf -> (B, D) in
+    batch order."""
+    return jnp.swapaxes(z, 0, 1).reshape(-1, z.shape[-1])
 
 
 def contrastive_step(encode_image: Callable, encode_text: Callable,
@@ -74,9 +85,8 @@ def contrastive_step(encode_image: Callable, encode_text: Callable,
         return None, (encode_image(params, img), encode_text(params, txt))
 
     _, (X, Y) = jax.lax.scan(fwd, None, (images, texts))
-    D = X.shape[-1]
-    X = _pin(X.reshape(-1, D))
-    Y = _pin(Y.reshape(-1, D))
+    X = _pin(_merge(X))
+    Y = _pin(_merge(Y))
 
     # ---- lines 6-12: loss on embeddings + d(loss)/d(X, Y, log_tau) ----
     def loss_on_emb(x, y, log_tau):
@@ -87,8 +97,8 @@ def contrastive_step(encode_image: Callable, encode_text: Callable,
         loss_on_emb, argnums=(0, 1, 2), has_aux=True)(
             X, Y, params["log_tau"])
 
-    dXm = _pin(dX).reshape(num_micro, -1, D)
-    dYm = _pin(dY).reshape(num_micro, -1, D)
+    dXm = _split(_pin(dX), num_micro)
+    dYm = _split(_pin(dY), num_micro)
 
     # ---- pass 2: rematerialize per microbatch, VJP into weights ----
     zero = jax.tree.map(jnp.zeros_like, params)
@@ -123,8 +133,7 @@ def microbatch_grads(encode_image: Callable, encode_text: Callable,
         return None, (encode_image(params, img), encode_text(params, txt))
 
     _, (X, Y) = jax.lax.scan(fwd, None, (images, texts))
-    D = X.shape[-1]
-    Xf, Yf = X.reshape(-1, D), Y.reshape(-1, D)
+    Xf, Yf = _merge(X), _merge(Y)
 
     def loss_on_emb(x, y, log_tau):
         tau = jnp.exp(log_tau)
@@ -133,8 +142,8 @@ def microbatch_grads(encode_image: Callable, encode_text: Callable,
     (loss, metrics), (dX, dY, dlog_tau) = jax.value_and_grad(
         loss_on_emb, argnums=(0, 1, 2), has_aux=True)(
             Xf, Yf, params["log_tau"])
-    dXm = dX.reshape(num_micro, -1, D)
-    dYm = dY.reshape(num_micro, -1, D)
+    dXm = _split(dX, num_micro)
+    dYm = _split(dY, num_micro)
 
     def one(mb):
         img, txt, dx, dy = mb

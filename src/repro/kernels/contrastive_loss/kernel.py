@@ -9,16 +9,17 @@ Single-pass kernels (DESIGN.md §2.3) — the default path, 2 launches total:
   _fused_fwd_kernel : grid (nI, nJ) -> row LSE and col LSE in ONE sweep.
       Row LSE runs the usual online rescale over the inner j axis (row
       running max/sum live in VMEM scratch, finalized at j == nJ-1).
-      Col LSE is carried in full-length VMEM scratch across the OUTER i
-      axis: each tile updates the (bn,)-slice of the (B,) column running
-      max/sum, finalized into the resident output at i == nI-1.
+      Col LSE is carried across the OUTER i axis in VMEM scratch of
+      shape (nJ, 1, bn): tile j updates slab j (a leading-dim index,
+      which Mosaic addresses without any alignment proof), finalized
+      into the resident output at i == nI-1.
   _fused_bwd_kernel : grid (nI, nJ) -> dX, dY, dlog_tau in ONE sweep.
       Each X·Yᵀ tile is computed once and contracted both ways: dX_i
       accumulates in its streamed output block over the inner j axis; dY
       accumulates slice-wise into a VMEM-resident (B, D) fp32 output
       (constant index map) across the outer i axis; dτ is a resident
-      scalar. Versus the legacy 4-pass path this halves X·Yᵀ matmul FLOPs
-      and roughly halves HBM reads of X/Y.
+      SMEM scalar. Versus the legacy 4-pass path this halves X·Yᵀ matmul
+      FLOPs and roughly halves HBM reads of X/Y.
 
 Legacy 4-pass kernels (kept for the perf-regression baseline in
 benchmarks/kernel_bench.py; each a clean single-reduction grid):
@@ -31,8 +32,17 @@ Backward recomputes each tile from (row_lse, col_lse):
   dA_ij = (exp(A_ij - row_lse_i) + exp(A_ij - col_lse_j) - 2·δ_ij) / (2B)
 
 Inputs may be bf16 (fed straight to the MXU with fp32 accumulation via
-``preferred_element_type``) or fp32. Block sizes are multiples of (8, 128)
-sublane×lane tiling; D is kept whole in VMEM (embedding dims here are
+``preferred_element_type``) or fp32.
+
+Layouts (what Mosaic accepts on a real TPU): no block is 1-D. Row
+statistics travel as (B, 1) columns in (bm, 1) blocks and column
+statistics as (1, B) rows in (1, bn) blocks — the shapes a keepdims
+reduction over the tile's lanes / sublanes produces, so no in-kernel
+relayout. The scalar 1/τ is a (1, 1) SMEM input and dτ a (1, 1) SMEM
+output. The public wrappers take and return (B,) vectors.
+
+Block sizes are multiples of (8, 128) sublane×lane tiling; D is kept
+whole in VMEM (embedding dims here are
 ≤ 2048 ⇒ X/Y tiles of bm×D ≤ 1 MB each). The VMEM footprint model behind
 block selection is in ops.pick_blocks (DESIGN.md §2.4).
 """
@@ -46,6 +56,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+
+_SCALAR = pl.BlockSpec(memory_space=pltpu.SMEM)     # (1, 1) fp32 scalar
+
+
+def _scalar(v):
+    return jnp.reshape(jnp.asarray(v, jnp.float32), (1, 1))
+
+
+def _rows(bm, order="ij"):
+    """(bm, 1) block of a (B, 1) row-statistic column, for grid order
+    ``ij`` (i outer) or ``ji`` (j outer)."""
+    if order == "ij":
+        return pl.BlockSpec((bm, 1), lambda i, j: (i, 0))
+    return pl.BlockSpec((bm, 1), lambda j, i: (i, 0))
+
+
+def _cols(bn, order="ij"):
+    """(1, bn) block of a (1, B) column-statistic row."""
+    if order == "ij":
+        return pl.BlockSpec((1, bn), lambda i, j: (0, j))
+    return pl.BlockSpec((1, bn), lambda j, i: (0, j))
 
 
 def _tile(x_ref, y_ref, inv_tau):
@@ -62,10 +93,11 @@ def _contract(da, v_ref):
 
 
 def _online_update(m, s, a, axis):
-    """One online-LSE step: returns updated (max, sum) over ``axis`` of a."""
-    m_new = jnp.maximum(m, jnp.max(a, axis=axis))
-    exp_a = jnp.exp(a - (m_new[:, None] if axis == 1 else m_new[None, :]))
-    s_new = s * jnp.exp(m - m_new) + jnp.sum(exp_a, axis=axis)
+    """One online-LSE step over ``axis`` of a; m/s keep their 2-D shape
+    ((bm, 1) for axis=1, (1, bn) for axis=0)."""
+    m_new = jnp.maximum(m, jnp.max(a, axis=axis, keepdims=True))
+    s_new = s * jnp.exp(m - m_new) + jnp.sum(jnp.exp(a - m_new), axis=axis,
+                                             keepdims=True)
     return m_new, s_new
 
 
@@ -75,7 +107,7 @@ def _online_update(m, s, a, axis):
 
 
 def _fused_fwd_kernel(x_ref, y_ref, inv_tau_ref, rlse_ref, clse_ref,
-                      rm, rs, cm, cs, *, bn, ni, nj):
+                      rm, rs, cm, cs, *, ni, nj):
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -83,17 +115,15 @@ def _fused_fwd_kernel(x_ref, y_ref, inv_tau_ref, rlse_ref, clse_ref,
         rm[...] = jnp.full_like(rm, NEG)
         rs[...] = jnp.zeros_like(rs)
 
-    @pl.when((i == 0) & (j == 0))
+    @pl.when(i == 0)
     def _init_col():
-        cm[...] = jnp.full_like(cm, NEG)
-        cs[...] = jnp.zeros_like(cs)
+        cm[j] = jnp.full(cm.shape[1:], NEG, jnp.float32)
+        cs[j] = jnp.zeros(cs.shape[1:], jnp.float32)
 
-    a = _tile(x_ref, y_ref, inv_tau_ref[0])            # (bm, bn)
+    a = _tile(x_ref, y_ref, inv_tau_ref[0, 0])         # (bm, bn)
 
     rm[...], rs[...] = _online_update(rm[...], rs[...], a, axis=1)
-
-    sl = pl.ds(j * bn, bn)
-    cm[sl], cs[sl] = _online_update(cm[sl], cs[sl], a, axis=0)
+    cm[j], cs[j] = _online_update(cm[j], cs[j], a, axis=0)
 
     @pl.when(j == nj - 1)
     def _finalize_row():
@@ -101,7 +131,7 @@ def _fused_fwd_kernel(x_ref, y_ref, inv_tau_ref, rlse_ref, clse_ref,
 
     @pl.when(i == ni - 1)
     def _finalize_col():
-        clse_ref[sl] = cm[sl] + jnp.log(cs[sl])
+        clse_ref[j] = cm[j] + jnp.log(cs[j])
 
 
 def fwd_fused(x, y, inv_tau, *, bm=128, bn=128, interpret=False):
@@ -109,29 +139,30 @@ def fwd_fused(x, y, inv_tau, *, bm=128, bn=128, interpret=False):
     b, d = x.shape
     assert b % bm == 0 and b % bn == 0, (b, bm, bn)
     ni, nj = b // bm, b // bn
-    inv_tau = jnp.asarray([inv_tau], jnp.float32)
 
-    return pl.pallas_call(
-        functools.partial(_fused_fwd_kernel, bn=bn, ni=ni, nj=nj),
+    rlse, clse = pl.pallas_call(
+        functools.partial(_fused_fwd_kernel, ni=ni, nj=nj),
         grid=(ni, nj),
         in_specs=[
             pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            _SCALAR,
         ],
         out_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((b,), lambda i, j: (0,)),
+            _rows(bm),
+            pl.BlockSpec((nj, 1, bn), lambda i, j: (0, 0, 0)),
         ],
-        out_shape=[jax.ShapeDtypeStruct((b,), jnp.float32)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((b, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((nj, 1, bn), jnp.float32)],
         scratch_shapes=[
-            pltpu.VMEM((bm,), jnp.float32),   # row running max
-            pltpu.VMEM((bm,), jnp.float32),   # row running sum
-            pltpu.VMEM((b,), jnp.float32),    # col running max (full length)
-            pltpu.VMEM((b,), jnp.float32),    # col running sum (full length)
+            pltpu.VMEM((bm, 1), jnp.float32),       # row running max
+            pltpu.VMEM((bm, 1), jnp.float32),       # row running sum
+            pltpu.VMEM((nj, 1, bn), jnp.float32),   # col running max
+            pltpu.VMEM((nj, 1, bn), jnp.float32),   # col running sum
         ],
         interpret=interpret,
-    )(x, y, inv_tau)
+    )(x, y, _scalar(inv_tau))
+    return rlse.reshape(b), clse.reshape(b)
 
 
 # ---------------------------------------------------------------------------
@@ -146,6 +177,15 @@ def _diag_mask(i, j, bm, bn):
     return (rows == cols).astype(jnp.float32)
 
 
+def _dlogits(a, rlse_ref, clse_ref, i, j, *, bm, bn, b_norm, with_diag):
+    """dLoss/dA for the (i, j) tile from the (bm, 1) row and (1, bn)
+    column LSE blocks."""
+    da = jnp.exp(a - rlse_ref[...]) + jnp.exp(a - clse_ref[...])
+    if with_diag:
+        da = da - 2.0 * _diag_mask(i, j, bm, bn)
+    return da / (2.0 * b_norm)
+
+
 def _fused_bwd_kernel(x_ref, y_ref, inv_tau_ref, rlse_ref, clse_ref,
                       dx_ref, dy_ref, dtau_ref, *, bm, bn, b_norm, with_diag):
     i, j = pl.program_id(0), pl.program_id(1)
@@ -156,20 +196,16 @@ def _fused_bwd_kernel(x_ref, y_ref, inv_tau_ref, rlse_ref, clse_ref,
 
     @pl.when((i == 0) & (j == 0))
     def _init_dtau():
-        dtau_ref[...] = jnp.zeros_like(dtau_ref)
+        dtau_ref[0, 0] = 0.0
 
-    inv_tau = inv_tau_ref[0]
+    inv_tau = inv_tau_ref[0, 0]
     a = _tile(x_ref, y_ref, inv_tau)
-    p_row = jnp.exp(a - rlse_ref[...][:, None])
-    p_col = jnp.exp(a - clse_ref[...][None, :])
-    da = p_row + p_col
-    if with_diag:
-        da = da - 2.0 * _diag_mask(i, j, bm, bn)
-    da = da / (2.0 * b_norm)
+    da = _dlogits(a, rlse_ref, clse_ref, i, j, bm=bm, bn=bn, b_norm=b_norm,
+                  with_diag=with_diag)
 
     dx_ref[...] += _contract(da, y_ref) * inv_tau
     dy_contrib = _contract(da.T, x_ref) * inv_tau
-    sl = pl.ds(j * bn, bn)
+    sl = pl.ds(pl.multiple_of(j * bn, bn), bn)
 
     @pl.when(i == 0)
     def _dy_first():
@@ -179,7 +215,7 @@ def _fused_bwd_kernel(x_ref, y_ref, inv_tau_ref, rlse_ref, clse_ref,
     def _dy_accum():
         dy_ref[sl, :] += dy_contrib
 
-    dtau_ref[...] += -jnp.sum(da * a)
+    dtau_ref[0, 0] += -jnp.sum(da * a)
 
 
 def bwd_fused(x, y, inv_tau, row_lse, col_lse, *, bm=128, bn=128,
@@ -194,7 +230,6 @@ def bwd_fused(x, y, inv_tau, row_lse, col_lse, *, bm=128, bn=128,
     b, d = x.shape
     assert b % bm == 0 and b % bn == 0, (b, bm, bn)
     ni, nj = b // bm, b // bn
-    inv_tau = jnp.asarray([inv_tau], jnp.float32)
 
     dx, dy, dtau = pl.pallas_call(
         functools.partial(_fused_bwd_kernel, bm=bm, bn=bn,
@@ -204,21 +239,21 @@ def bwd_fused(x, y, inv_tau, row_lse, col_lse, *, bm=128, bn=128,
         in_specs=[
             pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
+            _SCALAR,
+            _rows(bm),
+            _cols(bn),
         ],
         out_specs=[
             pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
             pl.BlockSpec((b, d), lambda i, j: (0, 0)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            _SCALAR,
         ],
         out_shape=[jax.ShapeDtypeStruct((b, d), jnp.float32),
                    jax.ShapeDtypeStruct((b, d), jnp.float32),
-                   jax.ShapeDtypeStruct((1,), jnp.float32)],
+                   jax.ShapeDtypeStruct((1, 1), jnp.float32)],
         interpret=interpret,
-    )(x, y, inv_tau, row_lse, col_lse)
-    return dx, dy, dtau[0]
+    )(x, y, _scalar(inv_tau), row_lse.reshape(b, 1), col_lse.reshape(1, b))
+    return dx, dy, dtau[0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -226,7 +261,7 @@ def bwd_fused(x, y, inv_tau, row_lse, col_lse, *, bm=128, bn=128,
 # ---------------------------------------------------------------------------
 
 
-def _row_lse_kernel(x_ref, y_ref, inv_tau_ref, m_ref, s_ref, *, nj):
+def _row_lse_kernel(x_ref, y_ref, inv_tau_ref, m_ref, s_ref):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -234,21 +269,20 @@ def _row_lse_kernel(x_ref, y_ref, inv_tau_ref, m_ref, s_ref, *, nj):
         m_ref[...] = jnp.full_like(m_ref, NEG)
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    a = _tile(x_ref, y_ref, inv_tau_ref[0])            # (bm, bn)
+    a = _tile(x_ref, y_ref, inv_tau_ref[0, 0])         # (bm, bn)
     m_ref[...], s_ref[...] = _online_update(m_ref[...], s_ref[...], a, axis=1)
 
 
-def _col_lse_kernel(y_ref, x_ref, inv_tau_ref, m_ref, s_ref, *, ni):
-    i = pl.program_id(1)
+def _col_lse_kernel(x_ref, y_ref, inv_tau_ref, m_ref, s_ref):
+    i = pl.program_id(1)                              # grid = (nJ, nI)
 
     @pl.when(i == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG)
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    # tile = X_i · Y_j^T transposed -> (bn, bm) scores of columns vs rows
-    a = _tile(y_ref, x_ref, inv_tau_ref[0])            # (bn, bm)
-    m_ref[...], s_ref[...] = _online_update(m_ref[...], s_ref[...], a, axis=1)
+    a = _tile(x_ref, y_ref, inv_tau_ref[0, 0])         # (bm, bn)
+    m_ref[...], s_ref[...] = _online_update(m_ref[...], s_ref[...], a, axis=0)
 
 
 def _dx_kernel(x_ref, y_ref, inv_tau_ref, rlse_ref, clse_ref,
@@ -261,20 +295,17 @@ def _dx_kernel(x_ref, y_ref, inv_tau_ref, rlse_ref, clse_ref,
 
     @pl.when((i == 0) & (j == 0))
     def _init2():
-        dtau_ref[...] = jnp.zeros_like(dtau_ref)
+        dtau_ref[0, 0] = 0.0
 
-    a = _tile(x_ref, y_ref, inv_tau_ref[0])
-    p_row = jnp.exp(a - rlse_ref[...][:, None])
-    p_col = jnp.exp(a - clse_ref[...][None, :])
-    da = p_row + p_col
-    if with_diag:
-        da = da - 2.0 * _diag_mask(i, j, bm, bn)
-    da = da / (2.0 * b_norm)
-    dx_ref[...] += _contract(da, y_ref) * inv_tau_ref[0]
-    dtau_ref[...] += -jnp.sum(da * a)
+    inv_tau = inv_tau_ref[0, 0]
+    a = _tile(x_ref, y_ref, inv_tau)
+    da = _dlogits(a, rlse_ref, clse_ref, i, j, bm=bm, bn=bn, b_norm=b_norm,
+                  with_diag=with_diag)
+    dx_ref[...] += _contract(da, y_ref) * inv_tau
+    dtau_ref[0, 0] += -jnp.sum(da * a)
 
 
-def _dy_kernel(y_ref, x_ref, inv_tau_ref, rlse_ref, clse_ref, dy_ref,
+def _dy_kernel(x_ref, y_ref, inv_tau_ref, rlse_ref, clse_ref, dy_ref,
                *, bm, bn, b_norm, with_diag):
     j, i = pl.program_id(0), pl.program_id(1)
 
@@ -282,55 +313,46 @@ def _dy_kernel(y_ref, x_ref, inv_tau_ref, rlse_ref, clse_ref, dy_ref,
     def _init():
         dy_ref[...] = jnp.zeros_like(dy_ref)
 
-    a_t = _tile(y_ref, x_ref, inv_tau_ref[0])          # (bn, bm): A_ij^T
-    p_row = jnp.exp(a_t - rlse_ref[...][None, :])      # softmax over rows of A
-    p_col = jnp.exp(a_t - clse_ref[...][:, None])
-    da_t = p_row + p_col
-    if with_diag:
-        da_t = da_t - 2.0 * _diag_mask(j, i, bn, bm)
-    da_t = da_t / (2.0 * b_norm)
-    dy_ref[...] += _contract(da_t, x_ref) * inv_tau_ref[0]
+    inv_tau = inv_tau_ref[0, 0]
+    a = _tile(x_ref, y_ref, inv_tau)                   # (bm, bn)
+    da = _dlogits(a, rlse_ref, clse_ref, i, j, bm=bm, bn=bn, b_norm=b_norm,
+                  with_diag=with_diag)
+    dy_ref[...] += _contract(da.T, x_ref) * inv_tau
 
 
 def row_col_lse(x, y, inv_tau, *, bm=128, bn=128, interpret=False):
     b, d = x.shape
     assert b % bm == 0 and b % bn == 0, (b, bm, bn)
     ni, nj = b // bm, b // bn
-    inv_tau = jnp.asarray([inv_tau], jnp.float32)
+    inv_tau = _scalar(inv_tau)
 
     rm, rs = pl.pallas_call(
-        functools.partial(_row_lse_kernel, nj=nj),
+        _row_lse_kernel,
         grid=(ni, nj),
         in_specs=[
             pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            _SCALAR,
         ],
-        out_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((b,), jnp.float32)] * 2,
+        out_specs=[_rows(bm), _rows(bm)],
+        out_shape=[jax.ShapeDtypeStruct((b, 1), jnp.float32)] * 2,
         interpret=interpret,
     )(x, y, inv_tau)
-    row_lse = rm + jnp.log(rs)
+    row_lse = (rm + jnp.log(rs)).reshape(b)
 
     cm, cs = pl.pallas_call(
-        functools.partial(_col_lse_kernel, ni=ni),
+        _col_lse_kernel,
         grid=(nj, ni),
         in_specs=[
-            pl.BlockSpec((bn, d), lambda j, i: (j, 0)),
             pl.BlockSpec((bm, d), lambda j, i: (i, 0)),
-            pl.BlockSpec((1,), lambda j, i: (0,)),
+            pl.BlockSpec((bn, d), lambda j, i: (j, 0)),
+            _SCALAR,
         ],
-        out_specs=[
-            pl.BlockSpec((bn,), lambda j, i: (j,)),
-            pl.BlockSpec((bn,), lambda j, i: (j,)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((b,), jnp.float32)] * 2,
+        out_specs=[_cols(bn, "ji"), _cols(bn, "ji")],
+        out_shape=[jax.ShapeDtypeStruct((1, b), jnp.float32)] * 2,
         interpret=interpret,
-    )(y, x, inv_tau)
-    col_lse = cm + jnp.log(cs)
+    )(x, y, inv_tau)
+    col_lse = (cm + jnp.log(cs)).reshape(b)
     return row_lse, col_lse
 
 
@@ -340,8 +362,9 @@ def grads(x, y, inv_tau, row_lse, col_lse, *, bm=128, bn=128,
     backward; ``b_norm``/``with_diag`` as in :func:`bwd_fused`)."""
     b, d = x.shape
     ni, nj = b // bm, b // bn
-    inv_tau = jnp.asarray([inv_tau], jnp.float32)
+    inv_tau = _scalar(inv_tau)
     b_norm = b if b_norm is None else b_norm
+    row_lse, col_lse = row_lse.reshape(b, 1), col_lse.reshape(1, b)
 
     dx, dtau = pl.pallas_call(
         functools.partial(_dx_kernel, bm=bm, bn=bn, b_norm=b_norm,
@@ -350,16 +373,16 @@ def grads(x, y, inv_tau, row_lse, col_lse, *, bm=128, bn=128,
         in_specs=[
             pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
+            _SCALAR,
+            _rows(bm),
+            _cols(bn),
         ],
         out_specs=[
             pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            _SCALAR,
         ],
         out_shape=[jax.ShapeDtypeStruct((b, d), jnp.float32),
-                   jax.ShapeDtypeStruct((1,), jnp.float32)],
+                   jax.ShapeDtypeStruct((1, 1), jnp.float32)],
         interpret=interpret,
     )(x, y, inv_tau, row_lse, col_lse)
 
@@ -368,14 +391,14 @@ def grads(x, y, inv_tau, row_lse, col_lse, *, bm=128, bn=128,
                           with_diag=with_diag),
         grid=(nj, ni),
         in_specs=[
-            pl.BlockSpec((bn, d), lambda j, i: (j, 0)),
             pl.BlockSpec((bm, d), lambda j, i: (i, 0)),
-            pl.BlockSpec((1,), lambda j, i: (0,)),
-            pl.BlockSpec((bm,), lambda j, i: (i,)),
-            pl.BlockSpec((bn,), lambda j, i: (j,)),
+            pl.BlockSpec((bn, d), lambda j, i: (j, 0)),
+            _SCALAR,
+            _rows(bm, "ji"),
+            _cols(bn, "ji"),
         ],
         out_specs=pl.BlockSpec((bn, d), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
         interpret=interpret,
-    )(y, x, inv_tau, row_lse, col_lse)
-    return dx, dy, dtau[0]
+    )(x, y, inv_tau, row_lse, col_lse)
+    return dx, dy, dtau[0, 0]

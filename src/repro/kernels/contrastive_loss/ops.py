@@ -40,6 +40,9 @@ _AUTOTUNE_CACHE: dict = {}
 # backward keeps a (B, D) fp32 dY carrier resident for the whole sweep
 # (DESIGN.md §2.3); when carrier + block working set can't fit, the compiled
 # path falls back to the legacy two-sweep backward (3 launches total).
+# Checked against Mosaic for TPU v5e (tests/test_tpu_compile.py): every
+# shape this rule accepts compiles, and the first refusals (D=512 fp32
+# B=6144, D=1024 B=3072) are rejected by it.
 _VMEM_TOTAL_APPROX = 14 * 2**20
 
 
@@ -47,6 +50,17 @@ def bwd_fits_fused(b: int, d: int, bm: int, bn: int, itemsize: int) -> bool:
     """True when the single-pass backward's VMEM residency is compilable:
     the (B, D) fp32 dY carrier plus the per-step block working set."""
     return block_bytes(bm, bn, d, itemsize) + b * d * 4 <= _VMEM_TOTAL_APPROX
+
+
+def backward_sweep(b: int, d: int, itemsize: int, *, bm: int | None = None,
+                   bn: int | None = None, interpret: bool = False) -> str:
+    """Which backward the loss runs at this shape: ``"fused"`` (one sweep,
+    resident dY carrier) or ``"legacy"`` (two sweeps). Interpret mode has
+    no VMEM limit and always takes the fused sweep."""
+    bm, bn = pick_blocks(b, d, itemsize, bm=bm, bn=bn)
+    if interpret or bwd_fits_fused(b, d, bm, bn, itemsize):
+        return "fused"
+    return "legacy"
 
 
 def block_bytes(bm: int, bn: int, d: int, itemsize: int) -> int:
@@ -174,9 +188,8 @@ def _bwd(interpret, bm, bn, res, g):
     b, d = x.shape
     bm, bn = pick_blocks(b, d, x.dtype.itemsize, bm=bm, bn=bn)
     inv_tau = jnp.exp(-log_tau)
-    # interpret mode has no VMEM limit; compiled mode needs the resident dY
-    # carrier to fit, else the legacy two-sweep backward keeps us correct
-    if interpret or bwd_fits_fused(b, d, bm, bn, x.dtype.itemsize):
+    if backward_sweep(b, d, x.dtype.itemsize, bm=bm, bn=bn,
+                      interpret=interpret) == "fused":
         dx, dy, dtau = kernel.bwd_fused(x, y, inv_tau, row_lse, col_lse,
                                         bm=bm, bn=bn, interpret=interpret)
     else:
@@ -228,7 +241,8 @@ def chunk_grads(x, y_chunk, inv_tau, row_lse, col_lse_chunk, *, b_norm,
     as the square loss, DESIGN.md §2.3)."""
     b, d = x.shape
     bm, bn = pick_blocks(b, d, x.dtype.itemsize, bm=bm, bn=bn)
-    if interpret or bwd_fits_fused(b, d, bm, bn, x.dtype.itemsize):
+    if backward_sweep(b, d, x.dtype.itemsize, bm=bm, bn=bn,
+                      interpret=interpret) == "fused":
         return kernel.bwd_fused(x, y_chunk, inv_tau, row_lse, col_lse_chunk,
                                 bm=bm, bn=bn, interpret=interpret,
                                 b_norm=b_norm, with_diag=with_diag)

@@ -9,6 +9,12 @@ additive key bias (one row per batch*head, e.g. -inf on padded text
 positions) rides in as a (1, block_k) tile. GQA is handled by the ops.py
 wrapper mapping each q head to its kv group.
 
+Per-row vectors (key bias, LSE, delta) live in HBM as (bh, 1, n) arrays
+read through (None, 1, block) blocks: lane-dense, and legal for Mosaic's
+(8, 128) rule on the last two block dims. The running max/sum scratch is
+(block_q, 1), and LSE/delta cross between the two orientations with a
+2-D transpose.
+
 Backward is the standard two-kernel flash split over the same tiles:
   dq  grid (bh, q_blocks, k_blocks), k innermost — dQ accumulates in VMEM
   dkv grid (bh, k_blocks, q_blocks), q innermost — dK/dV accumulate in VMEM
@@ -33,6 +39,26 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+def _vec(x):
+    """(bh, n) per-row vector -> the (bh, 1, n) fp32 kernel layout."""
+    if x is None:
+        return None
+    return x.astype(jnp.float32).reshape(x.shape[0], 1, x.shape[1])
+
+
+def _vec_spec(block, axis, kv_major=False):
+    """(1, block) tile of a (bh, 1, n) vector, indexed by the q-block
+    (``axis="i"``) or k-block (``"j"``) grid coordinate. The forward and
+    dq grids are (bh, i, j); the dkv grid is (bh, j, i) (``kv_major``)."""
+    if kv_major:
+        pick = (lambda b, j, i: (b, 0, i)) if axis == "i" else \
+            (lambda b, j, i: (b, 0, j))
+    else:
+        pick = (lambda b, i, j: (b, 0, i)) if axis == "i" else \
+            (lambda b, i, j: (b, 0, j))
+    return pl.BlockSpec((None, 1, block), pick)
 
 
 def _tile_mask(shape, qi, ki, block_q, block_k, causal, window, seq_k):
@@ -70,18 +96,17 @@ def _fwd_kernel(*refs, scale, block_q, block_k, causal, window, seq_k,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if b_ref is not None:
-        s = s + b_ref[0].astype(jnp.float32)[None, :]
+        s = s + b_ref[...]                                 # (1, bk)
     mask = _tile_mask(s.shape, qi, ki, block_q, block_k, causal, window,
                       seq_k)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev, l_prev = m_scr[...], l_scr[...]
-    m_cur = jnp.max(s, axis=1)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new[:, None])
+    m_prev, l_prev = m_scr[...], l_scr[...]                # (bq, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
         p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_scr[...] = m_new
@@ -90,8 +115,8 @@ def _fwd_kernel(*refs, scale, block_q, block_k, causal, window, seq_k,
     @pl.when(ki == nk - 1)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[...] + jnp.log(l)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[...] = (m_scr[...] + jnp.log(l)).T          # (1, bq)
 
 
 def flash_fwd_bh(q, k, v, bias=None, *, causal=True, window=None,
@@ -114,10 +139,10 @@ def flash_fwd_bh(q, k, v, bias=None, *, causal=True, window=None,
     ]
     args = [q, k, v]
     if bias is not None:
-        in_specs.append(pl.BlockSpec((1, block_k), lambda b, i, j: (b, j)))
-        args.append(bias.astype(jnp.float32))
+        in_specs.append(_vec_spec(block_k, "j"))
+        args.append(_vec(bias))
 
-    return pl.pallas_call(
+    out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, block_q=block_q,
                           block_k=block_k, causal=causal, window=window,
                           seq_k=t, has_bias=bias is not None),
@@ -125,19 +150,20 @@ def flash_fwd_bh(q, k, v, bias=None, *, causal=True, window=None,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+            _vec_spec(block_q, "i"),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((bh, s), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
     )(*args)
+    return out, lse.reshape(bh, s)
 
 
 # ---------------------------------------------------------------------------
@@ -155,16 +181,16 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, d_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if b_ref is not None:
-        s = s + b_ref[0].astype(jnp.float32)[None, :]
+        s = s + b_ref[...]                                 # (1, bk)
     mask = _tile_mask(s.shape, qi, ki, block_q, block_k, causal, window,
                       seq_k)
     s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse_ref[0][:, None])                   # (bq, bk)
+    p = jnp.exp(s - lse_ref[...].T)                        # (bq, bk)
     do = do_ref[0].astype(jnp.float32)
     dp = jax.lax.dot_general(do, v_ref[0].astype(jnp.float32),
                              (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - d_ref[0][:, None])
+    ds = p * (dp - d_ref[...].T)
     return q, p, do, ds
 
 
@@ -245,16 +271,17 @@ def flash_bwd_bh(q, k, v, bias, out, lse, dout, *, causal=True, window=None,
     common = dict(scale=scale, block_q=block_q, block_k=block_k,
                   causal=causal, window=window, seq_k=t, has_bias=has_bias)
 
+    lse, delta, bias = _vec(lse), _vec(delta), _vec(bias)
+
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kv_spec_j = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
-    row_spec = pl.BlockSpec((1, block_q), lambda b, i, j: (b, i))
-    bias_spec_j = pl.BlockSpec((1, block_k), lambda b, i, j: (b, j))
+    row_spec = _vec_spec(block_q, "i")
 
     dq_in_specs = [q_spec, kv_spec_j, kv_spec_j]
     dq_args = [q, k, v]
     if has_bias:
-        dq_in_specs.append(bias_spec_j)
-        dq_args.append(bias.astype(jnp.float32))
+        dq_in_specs.append(_vec_spec(block_k, "j"))
+        dq_args.append(bias)
     dq_in_specs += [q_spec, row_spec, row_spec]
     dq_args += [dout, lse, delta]
 
@@ -271,14 +298,13 @@ def flash_bwd_bh(q, k, v, bias, out, lse, dout, *, causal=True, window=None,
     # dkv grid: (bh, k_blocks, q_blocks) — index_map args are (b, j, i)
     q_spec_i = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
     kv_spec = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-    row_spec_i = pl.BlockSpec((1, block_q), lambda b, j, i: (b, i))
-    bias_spec = pl.BlockSpec((1, block_k), lambda b, j, i: (b, j))
+    row_spec_i = _vec_spec(block_q, "i", kv_major=True)
 
     dkv_in_specs = [q_spec_i, kv_spec, kv_spec]
     dkv_args = [q, k, v]
     if has_bias:
-        dkv_in_specs.append(bias_spec)
-        dkv_args.append(bias.astype(jnp.float32))
+        dkv_in_specs.append(_vec_spec(block_k, "j", kv_major=True))
+        dkv_args.append(bias)
     dkv_in_specs += [q_spec_i, row_spec_i, row_spec_i]
     dkv_args += [dout, lse, delta]
 

@@ -21,6 +21,7 @@ import numpy as np
 from repro.configs import get_arch, smoke_variant
 from repro.data import load_tokenizer, world_for_tower
 from repro.data.synthetic import render_images
+from repro.launch import compile_cache
 from repro.models import dual_encoder as de
 from repro.serving import ZeroShotService
 
@@ -57,6 +58,7 @@ def main():
                          "(0 = ephemeral) for the whole run")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
     nprobe = None if args.nprobe in (None, "all") else int(args.nprobe)
 
     cfg = get_arch(args.arch)

@@ -91,6 +91,7 @@ from repro.configs import get_arch, smoke_variant
 from repro.core import sharding as shd
 from repro.core.remat import get_policy, list_policies
 from repro.data.pipeline import Prefetcher, host_rng
+from repro.launch import compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import frontends, transformer as tf
 from repro.optim import AdaFactorW, apply_updates, warmup_cosine
@@ -576,7 +577,9 @@ def train(args):
     return train_contrastive(args)
 
 
-def main():
+def parser() -> argparse.ArgumentParser:
+    """The trainer's command line (also how ``chip_smoke.py`` builds its
+    arguments, so both share every default)."""
     ap = argparse.ArgumentParser(
         description=__doc__.split("\n\n")[0],
         formatter_class=argparse.RawDescriptionHelpFormatter)
@@ -682,7 +685,12 @@ def main():
                          "checkpoint + clean exit) deterministically")
     ap.add_argument("--stop-after", type=int, default=None,
                     help="halt early but keep the --steps LR horizon")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = parser().parse_args()
+    compile_cache.enable()
     train(args)
 
 

@@ -32,12 +32,12 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import sharding as shd
 from repro.kernels.similarity_topk import ops as topk_ops
 from repro.kernels.similarity_topk.kernel import IDX_PAD, NEG
+from repro.launch.mesh import make_mesh
 
 
 def default_data_mesh(n_devices: Optional[int] = None):
@@ -46,7 +46,7 @@ def default_data_mesh(n_devices: Optional[int] = None):
     mesh is passed in."""
     devs = jax.devices()
     n = len(devs) if n_devices is None else int(n_devices)
-    return jax.make_mesh((n,), (shd.DATA,), devices=devs[:n])
+    return make_mesh((n,), (shd.DATA,), devices=devs[:n])
 
 
 def _round_up(x: int, m: int) -> int:
@@ -161,9 +161,9 @@ def sharded_similarity_topk(query_emb, class_emb, k: int, *, mesh=None,
         pool_i = jnp.moveaxis(ig, 0, 1).reshape(v.shape[0], -1)
         return topk_ops.merge_topk(pool_v, pool_i, k)
 
-    mapped = shard_map(local_fn, mesh=sm.mesh,
-                       in_specs=(P(), P(axis)), out_specs=(P(), P()),
-                       check_rep=False)
+    mapped = jax.shard_map(local_fn, mesh=sm.mesh,
+                           in_specs=(P(), P(axis)), out_specs=(P(), P()),
+                           check_vma=False)
     x = jnp.asarray(query_emb)
     with sm.mesh:
         vals, idx = jax.jit(mapped)(x, sm.array)

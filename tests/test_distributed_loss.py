@@ -15,6 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
+
 _CHECKS = os.path.join(os.path.dirname(__file__), "distributed_checks.py")
 
 
@@ -51,7 +53,7 @@ def test_make_global_loss_fn_single_extent_falls_back():
     from repro.core import distributed_loss as dl
     from repro.core.contrastive import fused_kernel_loss
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     kx, ky = jax.random.split(jax.random.key(3))
     x = jax.random.normal(kx, (32, 16))
     x = x / jnp.linalg.norm(x, axis=-1, keepdims=True)
@@ -66,7 +68,7 @@ def test_make_global_loss_fn_single_extent_falls_back():
 
 
 def test_make_global_loss_fn_rejects_unknown_method():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     from repro.core import distributed_loss as dl
     with pytest.raises(ValueError, match="method"):
         dl.make_global_loss_fn(mesh, "ring")

@@ -9,8 +9,8 @@ from repro.configs import INPUT_SHAPES, get_arch, list_archs
 from repro.core import sharding as shd
 from repro.launch import steps as st
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH_MP = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 ASSIGNED = [a for a in list_archs() if not a.startswith("basic-")]
 
 
