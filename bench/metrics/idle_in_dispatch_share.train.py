@@ -1,0 +1,10 @@
+"""Share of the traced window's device idle time (no op running,
+trace_reduce) during which the train loop's main thread was inside the
+call into the jitted step (``repro/train/dispatch``;
+bench/program_spans.py), in %."""
+from bench import program_spans as PS
+
+
+def compute(data, trace, peaks):
+    return PS.idle_share_under(trace, PS.for_trace(trace),
+                               "repro/train/dispatch")

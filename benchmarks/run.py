@@ -42,9 +42,6 @@ SUITES = {
     "ckpt": ("benchmarks.ckpt_bench",
              "checkpoint save stall: blocking vs async manager, plus "
              "verified restore (gated, DESIGN.md §10.5)"),
-    "obs": ("benchmarks.obs_bench",
-            "telemetry overhead: per-step instrumentation vs 5%-of-step "
-            "budget (gated, DESIGN.md §11.4)"),
     "decode": ("benchmarks.decode_bench",
                "continuous-batching decode vs one-at-a-time legacy "
                "serving (gated, DESIGN.md §12.5)"),
@@ -53,7 +50,7 @@ TABLES = {name: mod for name, (mod, _) in SUITES.items()}
 
 # slow full-sweep benches only run when selected explicitly (or via --json)
 _OPT_IN = {"kernels", "serving", "distributed", "tower", "data", "ckpt",
-           "obs", "decode"}
+           "decode"}
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -65,7 +62,6 @@ GATED = {
     "tower": os.path.join(_ROOT, "BENCH_tower.json"),
     "data": os.path.join(_ROOT, "BENCH_data.json"),
     "ckpt": os.path.join(_ROOT, "BENCH_ckpt.json"),
-    "obs": os.path.join(_ROOT, "BENCH_obs.json"),
     "decode": os.path.join(_ROOT, "BENCH_decode.json"),
 }
 

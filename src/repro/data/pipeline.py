@@ -32,7 +32,8 @@ class Prefetcher:
         self._start = start
         self._stop = threading.Event()
         self._error: BaseException = None
-        self._thread = threading.Thread(target=self._worker, daemon=True)
+        self._thread = threading.Thread(target=self._worker, daemon=True,
+                                        name="prefetch")
         self._thread.start()
 
     def _worker(self):

@@ -135,9 +135,8 @@ class ShardedLoader:
         self.augment = tuple(augment)
         self._step = int(start_step)
         # telemetry (DESIGN.md §11): per-host block-generation timing into
-        # ``registry`` histograms and ``tracer`` spans on pid lane
-        # 1+host_id (the trace's simulated-host lanes); both optional and
-        # free when None
+        # ``registry`` histograms and ``data/host_block`` spans carrying
+        # ``step`` and ``host`` (kept in ``tracer`` too when it is given)
         self._registry = registry
         self._tracer = tracer
         self._h_gen = None if registry is None else {
@@ -154,8 +153,8 @@ class ShardedLoader:
     # -- batch materialization --------------------------------------------
     def _block(self, step: int, host_id: int) -> dict:
         t0 = time.perf_counter()
-        with obs_trace.span(self._tracer, "host_block", pid=1 + host_id,
-                            step=step, host=host_id):
+        with obs_trace.span(self._tracer, "data/host_block", step=step,
+                            host=host_id):
             rng = host_rng(self.seed, host_id, step)
             batch, _ = contrastive_batch(self.world, self.tok,
                                          self.local_batch, rng,

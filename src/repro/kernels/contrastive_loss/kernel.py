@@ -160,6 +160,7 @@ def fwd_fused(x, y, inv_tau, *, bm=128, bn=128, interpret=False):
             pltpu.VMEM((nj, 1, bn), jnp.float32),   # col running max
             pltpu.VMEM((nj, 1, bn), jnp.float32),   # col running sum
         ],
+        name="contrastive_fused_fwd",
         interpret=interpret,
     )(x, y, _scalar(inv_tau))
     return rlse.reshape(b), clse.reshape(b)
@@ -251,6 +252,7 @@ def bwd_fused(x, y, inv_tau, row_lse, col_lse, *, bm=128, bn=128,
         out_shape=[jax.ShapeDtypeStruct((b, d), jnp.float32),
                    jax.ShapeDtypeStruct((b, d), jnp.float32),
                    jax.ShapeDtypeStruct((1, 1), jnp.float32)],
+        name="contrastive_fused_bwd",
         interpret=interpret,
     )(x, y, _scalar(inv_tau), row_lse.reshape(b, 1), col_lse.reshape(1, b))
     return dx, dy, dtau[0, 0]
@@ -336,6 +338,7 @@ def row_col_lse(x, y, inv_tau, *, bm=128, bn=128, interpret=False):
         ],
         out_specs=[_rows(bm), _rows(bm)],
         out_shape=[jax.ShapeDtypeStruct((b, 1), jnp.float32)] * 2,
+        name="contrastive_row_lse",
         interpret=interpret,
     )(x, y, inv_tau)
     row_lse = (rm + jnp.log(rs)).reshape(b)
@@ -350,6 +353,7 @@ def row_col_lse(x, y, inv_tau, *, bm=128, bn=128, interpret=False):
         ],
         out_specs=[_cols(bn, "ji"), _cols(bn, "ji")],
         out_shape=[jax.ShapeDtypeStruct((1, b), jnp.float32)] * 2,
+        name="contrastive_col_lse",
         interpret=interpret,
     )(x, y, inv_tau)
     col_lse = (cm + jnp.log(cs)).reshape(b)
@@ -383,6 +387,7 @@ def grads(x, y, inv_tau, row_lse, col_lse, *, bm=128, bn=128,
         ],
         out_shape=[jax.ShapeDtypeStruct((b, d), jnp.float32),
                    jax.ShapeDtypeStruct((1, 1), jnp.float32)],
+        name="contrastive_dx",
         interpret=interpret,
     )(x, y, inv_tau, row_lse, col_lse)
 
@@ -399,6 +404,7 @@ def grads(x, y, inv_tau, row_lse, col_lse, *, bm=128, bn=128,
         ],
         out_specs=pl.BlockSpec((bn, d), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
+        name="contrastive_dy",
         interpret=interpret,
     )(x, y, inv_tau, row_lse, col_lse)
     return dx, dy, dtau[0, 0]

@@ -122,5 +122,6 @@ def topk_fused(x, c, inv_tau, *, k, bm, bc, n_classes, n_valid=None,
             pltpu.VMEM((bm, k), jnp.float32),   # running top-k values
             pltpu.VMEM((bm, k), jnp.int32),     # running top-k class ids
         ],
+        name="similarity_topk",
         interpret=interpret,
     )(x, c, inv_tau, n_valid)

@@ -55,7 +55,11 @@ the loop streams one schema-versioned JSONL record per step to
 ``<run-dir>/runlog.jsonl`` — loss, grad-norm, examples/sec, and the
 data-wait / device-step / ckpt-stall breakdown — plus checkpoint /
 degrade / resume marker records, and exports a Chrome ``trace_event``
-JSON (``trace.json``, Perfetto-viewable, per-host pid lanes) on exit.
+JSON (``trace.json``, Perfetto-viewable) on exit. Each step also runs
+under ``jax.profiler.StepTraceAnnotation("train")`` with its phases as
+``repro/train/*`` spans (data_wait, dispatch, wait, ckpt_stall, log) and
+the prefetch thread's ``repro/data/render`` / ``repro/data/put``, so any
+profiler capture lines them up with the device's ops.
 ``--log-every N`` paces the human stdout line, ``--quiet`` silences it;
 summarize a run with ``python -m repro.obs.report <run-dir>/runlog.jsonl``.
 
@@ -225,10 +229,17 @@ def _run_loop(args, step_fn, params, opt_state, make_batch, start, *,
     JSONL record to ``runlog`` — loss, grad-norm, examples/sec, and the
     data-wait / device-step / ckpt-stall time breakdown — while stdout
     only gets the human line every ``--log-every`` steps (``--quiet``
-    silences it entirely). ``tracer`` records the same phases as spans;
-    the Chrome trace JSON is exported to ``<run_dir>/trace.json`` when
-    the loop ends. All of it is host-side work OUTSIDE the jitted step
-    (the ``benchmarks/obs_bench.py`` overhead gate pins it ≤1.05× bare).
+    silences it entirely). Each iteration runs under
+    ``StepTraceAnnotation("train", step_num=i)`` and its phases are
+    profiler spans carrying ``step``: ``train/data_wait`` (``next``),
+    ``train/dispatch`` (the call into the jitted step, until it
+    returns), ``train/wait`` (``float(loss)``, until the device step
+    ends), ``train/ckpt_stall`` and ``train/log`` (the metric reads, the
+    run-log write and the health monitor). The run log's
+    ``device_step_s`` is dispatch + wait. ``tracer`` keeps the same
+    spans in its ring; the Chrome trace JSON is exported to
+    ``<run_dir>/trace.json`` when the loop ends. All of it is host-side
+    work OUTSIDE the jitted step.
 
     Health (DESIGN.md §14): with a ``monitor`` every step's host-side
     floats feed the anomaly detectors (anomaly runlog records, trace
@@ -284,68 +295,76 @@ def _run_loop(args, step_fn, params, opt_state, make_batch, start, *,
                        sync=bool(final or manager.sync), stall_s=stall)
         return stall
 
+    def log_step(i, loss_f, metrics, **times):
+        """The step's metric reads, run-log record, health check and
+        human line."""
+        gnorm_f = (float(metrics["grad_norm"])
+                   if metrics.get("grad_norm") is not None else None)
+        skipped = bool(float(metrics.get("skipped", 0)))
+        step_rec = None
+        if runlog:
+            extra = {} if gnorm_f is None else {"grad_norm": gnorm_f}
+            if skipped:
+                extra["skipped"] = 1
+            step_rec = runlog.log_step(
+                i, loss=loss_f, examples_per_sec=args.batch / times["step_s"],
+                **times, **extra)
+        if monitor is not None:
+            monitor.observe_step(obs.StepSample(
+                step=i, loss=loss_f,
+                grad_norm=math.nan if gnorm_f is None else gnorm_f,
+                data_wait_s=times["data_wait_s"],
+                device_step_s=times["device_step_s"],
+                step_s=times["step_s"], skipped=skipped), record=step_rec)
+        if not quiet and (i % args.log_every == 0 or i == args.steps - 1):
+            gtxt = "" if gnorm_f is None else f"gnorm {gnorm_f:.2f} "
+            print(f"step {i:5d} loss {loss_f:.4f} {gtxt}"
+                  f"{(time.time()-t0)/max(1, i-start+1):.2f}s/step")
+
     final_saved = False
     try:
         for i in range(start, min(args.steps, stop)):
-            t_iter = time.perf_counter()
-            with obs_trace.span(tracer, "data_wait", step=i):
-                batch = next(stream)
-            batch = obs_health.apply_step_fault_hook(i, batch)
-            t_data = time.perf_counter()
-            with obs_trace.span(tracer, "device_step", step=i):
-                if step_takes_index:
-                    params, opt_state, loss, metrics = step_fn(
-                        params, opt_state, batch, jnp.asarray(i))
-                else:
-                    params, opt_state, loss, metrics = step_fn(
-                        params, opt_state, batch)
-                loss_f = float(loss)   # blocks until the device step ends
-            t_device = time.perf_counter()
-            losses.append(loss_f)
-            ckpt_stall, breaking = 0.0, False
-            if preempt_after is not None and i - start + 1 == preempt_after:
-                # simulated-preemption hook: deliver a REAL SIGTERM to
-                # ourselves so tests exercise the exact signal path
-                os.kill(os.getpid(), signal.SIGTERM)
-            if preempted.is_set():
-                if args.ckpt_dir:
-                    print(f"SIGTERM: preemption checkpoint at step {i + 1}")
-                    with obs_trace.span(tracer, "ckpt_stall", step=i):
-                        ckpt_stall += save(i + 1, final=True,
-                                           event="preempt_save")
-                final_saved = breaking = True
-            elif args.ckpt_dir and args.ckpt_every and \
-                    (i + 1) % args.ckpt_every == 0:
-                with obs_trace.span(tracer, "ckpt_stall", step=i):
-                    ckpt_stall += save(i + 1)
-            step_s = time.perf_counter() - t_iter
-            gnorm_f = (float(metrics["grad_norm"])
-                       if metrics.get("grad_norm") is not None else None)
-            skipped = bool(float(metrics.get("skipped", 0)))
-            step_rec = None
-            if runlog:
-                extra = {} if gnorm_f is None else {"grad_norm": gnorm_f}
-                if skipped:
-                    extra["skipped"] = 1
-                step_rec = runlog.log_step(
-                    i, loss=loss_f, data_wait_s=t_data - t_iter,
-                    device_step_s=t_device - t_data,
-                    ckpt_stall_s=ckpt_stall, step_s=step_s,
-                    examples_per_sec=args.batch / step_s, **extra)
-            if monitor is not None:
-                monitor.observe_step(obs.StepSample(
-                    step=i, loss=loss_f,
-                    grad_norm=math.nan if gnorm_f is None else gnorm_f,
-                    data_wait_s=t_data - t_iter,
-                    device_step_s=t_device - t_data, step_s=step_s,
-                    skipped=skipped), record=step_rec)
-            if not quiet and (i % args.log_every == 0
-                              or i == args.steps - 1):
-                gnorm = metrics.get("grad_norm")
-                gtxt = f"gnorm {float(gnorm):.2f} " \
-                    if gnorm is not None else ""
-                print(f"step {i:5d} loss {loss_f:.4f} {gtxt}"
-                      f"{(time.time()-t0)/max(1, i-start+1):.2f}s/step")
+            with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                t_iter = time.perf_counter()
+                with obs_trace.span(tracer, "train/data_wait", step=i):
+                    batch = next(stream)
+                batch = obs_health.apply_step_fault_hook(i, batch)
+                t_data = time.perf_counter()
+                with obs_trace.span(tracer, "train/dispatch", step=i):
+                    if step_takes_index:
+                        params, opt_state, loss, metrics = step_fn(
+                            params, opt_state, batch, jnp.asarray(i))
+                    else:
+                        params, opt_state, loss, metrics = step_fn(
+                            params, opt_state, batch)
+                with obs_trace.span(tracer, "train/wait", step=i):
+                    loss_f = float(loss)   # until the device step ends
+                t_device = time.perf_counter()
+                losses.append(loss_f)
+                ckpt_stall, breaking = 0.0, False
+                if preempt_after is not None and \
+                        i - start + 1 == preempt_after:
+                    # simulated-preemption hook: deliver a REAL SIGTERM to
+                    # ourselves so tests exercise the exact signal path
+                    os.kill(os.getpid(), signal.SIGTERM)
+                if preempted.is_set():
+                    if args.ckpt_dir:
+                        print(f"SIGTERM: preemption checkpoint at step "
+                              f"{i + 1}")
+                        with obs_trace.span(tracer, "train/ckpt_stall",
+                                            step=i):
+                            ckpt_stall += save(i + 1, final=True,
+                                               event="preempt_save")
+                    final_saved = breaking = True
+                elif args.ckpt_dir and args.ckpt_every and \
+                        (i + 1) % args.ckpt_every == 0:
+                    with obs_trace.span(tracer, "train/ckpt_stall", step=i):
+                        ckpt_stall += save(i + 1)
+                step_s = time.perf_counter() - t_iter
+                with obs_trace.span(tracer, "train/log", step=i):
+                    log_step(i, loss_f, metrics, data_wait_s=t_data - t_iter,
+                             device_step_s=t_device - t_data,
+                             ckpt_stall_s=ckpt_stall, step_s=step_s)
             if breaking:
                 break
     finally:
@@ -353,7 +372,8 @@ def _run_loop(args, step_fn, params, opt_state, make_batch, start, *,
         if prev_handler is not None:
             signal.signal(signal.SIGTERM, prev_handler)
     if args.ckpt_dir and not final_saved:
-        with obs_trace.span(tracer, "ckpt_stall"):
+        with obs_trace.span(tracer, "train/ckpt_stall",
+                            step=min(args.steps, stop)):
             save(min(args.steps, stop), final=True, event="final_save")
     if manager is not None:
         manager.close()
@@ -522,9 +542,6 @@ def train_contrastive(args):
         registry, tracer, runlog, run_dir = _make_obs(args, start)
         monitor, server = _make_health(args, registry, tracer, runlog,
                                        run_dir)
-        if tracer is not None:
-            for h in range(data_size):
-                tracer.set_process_name(1 + h, f"host {h}")
         # one host block per data shard: block h of the global batch lands
         # on data shard h, the §5.1 "distributed equally to all cores" layout
         loader = ShardedLoader(world, tok, args.batch,
@@ -541,7 +558,10 @@ def train_contrastive(args):
             loader.restore(LoaderState.from_json(meta["loader"]))
 
         def make_batch(step):
-            return device_put_global(loader.global_batch_at(step), mesh)
+            with obs_trace.span(tracer, "data/render", step=step):
+                host = loader.global_batch_at(step)
+            with obs_trace.span(tracer, "data/put", step=step):
+                return device_put_global(host, mesh)
 
         def ckpt_meta_fn(next_step):
             return {"loader": loader.state(step=next_step).to_json()}

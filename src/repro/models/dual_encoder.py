@@ -4,6 +4,11 @@ Paper §3: F(x), G(y) live on the D-dimensional unit sphere; similarity
 A = (X^T Y)/tau with learnable temperature tau (stored as log_tau).
 Text pooling is mean-over-positions (paper §7.2, unlike ALIGN's [CLS]).
 
+Each tower's ops run under ``jax.named_scope`` ("image_tower" /
+"text_tower", with "attention" / "mlp" inside the blocks): the compiled
+step's op metadata, which the profiler's op views read, then names the
+tower and the part of the block each op belongs to.
+
 Both encoders take a ``precision`` policy (models.precision): the towers
 run in its compute dtype while the embedding projections and the unit-norm
 always land in fp32 under the default policies — the contrastive loss (and
@@ -46,10 +51,11 @@ def encode_image(cfg: DualEncoderConfig, params, images, *, precision=None,
     """images: dict with 'image' (b, H, W, C) raw pixels (the tower's
     patchify frontend embeds them). Returns (b, D) on S^D, fp32."""
     pol = prec_lib.resolve(precision)
-    h = tf.encode(cfg.image_tower, params["image"]["tower"], images,
-                  precision=pol, remat_policy=remat_policy)
-    return _norm(L.dense(pol.project(h),
-                         params["image"]["proj"]).astype(jnp.float32))
+    with jax.named_scope("image_tower"):
+        h = tf.encode(cfg.image_tower, params["image"]["tower"], images,
+                      precision=pol, remat_policy=remat_policy)
+        return _norm(L.dense(pol.project(h),
+                             params["image"]["proj"]).astype(jnp.float32))
 
 
 def encode_text(cfg: DualEncoderConfig, params, texts, *, precision=None,
@@ -57,10 +63,11 @@ def encode_text(cfg: DualEncoderConfig, params, texts, *, precision=None,
     """texts: dict with 'tokens' (b, s) (+ optional 'attn_mask', which masks
     padding inside attention and pooling)."""
     pol = prec_lib.resolve(precision)
-    h = tf.encode(cfg.text_tower, params["text"]["tower"], texts,
-                  precision=pol, remat_policy=remat_policy)
-    return _norm(L.dense(pol.project(h),
-                         params["text"]["proj"]).astype(jnp.float32))
+    with jax.named_scope("text_tower"):
+        h = tf.encode(cfg.text_tower, params["text"]["tower"], texts,
+                      precision=pol, remat_policy=remat_policy)
+        return _norm(L.dense(pol.project(h),
+                             params["text"]["proj"]).astype(jnp.float32))
 
 
 def temperature(params):

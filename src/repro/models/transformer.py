@@ -106,9 +106,12 @@ def init_params(cfg: ArchConfig, rng):
 def _apply_block(cfg, kind, use_moe, p, h, positions, cache, decode, moe_args,
                  collect_cache_len=None, key_mask=None):
     aux = jnp.zeros((), jnp.float32)
-    if kind == "attn":
+    with jax.named_scope("attention" if kind == "attn" else "mixer"):
         hn = L.rms_norm(h, p["ln1"], cfg.norm_eps)
-        if decode:
+        if kind != "attn":
+            mixer = ssm_lib.mamba_decode if decode else ssm_lib.mamba_mixer
+            mix, new_cache = mixer(p["mamba"], cfg, hn, cache)
+        elif decode:
             mix, new_cache = attn_lib.decode_attention(
                 p["attn"], cfg, hn, cache, positions)
         elif collect_cache_len is not None:
@@ -121,19 +124,15 @@ def _apply_block(cfg, kind, use_moe, p, h, positions, cache, decode, moe_args,
             mix = attn_lib.attention(p["attn"], cfg, hn, positions,
                                      key_mask=key_mask)
             new_cache = None
-    else:
-        hn = L.rms_norm(h, p["ln1"], cfg.norm_eps)
-        if decode:
-            mix, new_cache = ssm_lib.mamba_decode(p["mamba"], cfg, hn, cache)
-        else:
-            mix, new_cache = ssm_lib.mamba_mixer(p["mamba"], cfg, hn, cache)
     h = h + mix
     if cfg.family != "ssm":
-        hn = L.rms_norm(h, p["ln2"], cfg.norm_eps)
-        if use_moe:
-            out, aux = moe_lib.moe_ffn(p["moe"], cfg, hn, **moe_args)
-        else:
-            out = L.swiglu(hn, p["ffn"]["wi"], p["ffn"]["wg"], p["ffn"]["wo"])
+        with jax.named_scope("mlp"):
+            hn = L.rms_norm(h, p["ln2"], cfg.norm_eps)
+            if use_moe:
+                out, aux = moe_lib.moe_ffn(p["moe"], cfg, hn, **moe_args)
+            else:
+                out = L.swiglu(hn, p["ffn"]["wi"], p["ffn"]["wg"],
+                               p["ffn"]["wo"])
         h = h + out
     return h, new_cache, aux
 

@@ -5,10 +5,11 @@ One stats mechanism repo-wide. The passive layers (§11):
   metrics  — process-wide registry of counters / gauges / fixed-bucket
              histograms (p50/p90/p99 summaries), thread-safe, labeled
              children, ``snapshot()``/``to_json()``.
-  trace    — ``span(...)`` context managers recording wall-time events
-             into a ring buffer, exportable as Chrome ``trace_event``
-             JSON (load in Perfetto / chrome://tracing), with per-host
-             ``pid`` lanes for the simulated multi-host runs.
+  trace    — ``span(...)``: ``jax.profiler`` annotations named
+             ``repro/<name>`` (the program's spans on the profiler's
+             clock, beside the device's ops), kept also in a ring buffer
+             exportable as Chrome ``trace_event`` JSON (Perfetto /
+             chrome://tracing).
   runlog   — one schema-versioned JSONL record per train step (loss,
              grad-norm, examples/sec, data-wait / device-step /
              ckpt-stall breakdown, checkpoint + retention + anomaly
@@ -27,9 +28,9 @@ And the active tier built on them (§14):
              endpoint (localhost-only by default).
 
 Everything is off-hot-path cheap: instruments mutate a couple of Python
-ints under a lock, snapshotting and JSONL writes happen outside the
-jitted step, and ``benchmarks/obs_bench.py`` gates the instrumented-vs-
-bare step overhead at ≤1.05× — health checks included.
+ints under a lock, a span with no profiler attached costs about a
+microsecond, and snapshotting and JSONL writes happen outside the jitted
+step.
 """
 from repro.obs.export import MetricsServer, render_prometheus
 from repro.obs.health import (Anomaly, Detector, FlightRecorder,

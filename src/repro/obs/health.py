@@ -24,9 +24,8 @@ Three pieces:
 
 Everything is optional and cheap: a monitor without a runlog/tracer just
 counts; detector checks are a handful of window pushes and one sorted
-percentile over <=256 floats (priced in ``benchmarks/obs_bench.py``
-``health/check`` against the same 5%-of-step budget as the passive
-telemetry). DESIGN.md §14 derives the MAD z-score threshold.
+percentile over <=256 floats. DESIGN.md §14 derives the MAD z-score
+threshold.
 """
 from __future__ import annotations
 

@@ -8,8 +8,7 @@ now thin views over these counters, back-compat tested).
 Design constraints, in order:
 
   * off-hot-path cheap: an ``inc``/``observe`` is a couple of Python int
-    ops under a per-instrument lock (measured in
-    ``benchmarks/obs_bench.py`` ``micro/*`` entries);
+    ops under a per-instrument lock;
   * thread-safe: instruments are mutated from the prefetch thread, the
     micro-batcher flush thread, and the checkpoint writer thread
     concurrently — every mutation and every read of an instrument's state

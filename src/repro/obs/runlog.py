@@ -23,9 +23,8 @@ Readers REJECT records from a different schema version (``RunlogError``)
 instead of guessing: the version only moves when the record shape does,
 and ``scripts/check_runlog.py`` gates committed samples against it.
 
-Writes are append-only line-buffered JSON — cheap enough for every step
-(``benchmarks/obs_bench.py`` ``micro/runlog_step``), crash-tolerant by
-construction (a torn final line is detected and reported by the reader,
+Writes are append-only line-buffered JSON — cheap enough for every step,
+crash-tolerant by construction (a torn final line is detected and reported by the reader,
 never fatal to earlier records).
 """
 from __future__ import annotations

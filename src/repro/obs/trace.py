@@ -1,35 +1,38 @@
-"""Span tracing: wall-time events in a ring buffer, Perfetto-exportable.
+"""Program spans on the profiler's clock, kept also in a ring buffer.
 
-``with tracer.span("data_wait"):`` records one complete event (begin +
-duration) into a bounded ring buffer — a long run never grows the buffer
-past ``capacity``, the newest events win (``dropped`` counts evictions).
-``to_chrome_trace()`` renders the buffer as Chrome ``trace_event`` JSON
-(the ``{"traceEvents": [...]}`` object form) that loads directly in
-Perfetto / ``chrome://tracing``; every event carries the required
-``ph/ts/dur/pid/tid/name`` keys.
+``with span(tracer, "train/dispatch", step=i):`` opens a
+``jax.profiler.TraceAnnotation`` named ``repro/train/dispatch`` with the
+span's arguments, whatever ``tracer`` is. Any profiler capture — a
+``jax.profiler.start_trace`` or an operator's client attached to
+``jax.profiler.start_server`` — then shows the program's spans on the same
+clock as the device's ops, each on the row of the thread that ran it. With
+no profiler attached a TraceMe costs about a microsecond.
 
-Lanes: ``pid`` is the LOGICAL process lane — the trainer records its
-data-wait / device-step / ckpt-stall spans on pid 0 while the simulated
-multi-host loader records each host's block generation on pid 1+host, so
-a single-process simulation renders as the multi-host timeline it models.
-``tid`` defaults to a small per-tracer id for the calling OS thread (the
-prefetch / flush / checkpoint-writer threads get their own rows).
+Where a ``Tracer`` is given, the span also goes into its bounded ring
+buffer (``capacity`` events, the newest win, ``dropped`` counts the
+evicted), timestamped from ``time.time_ns()``. ``to_chrome_trace()``
+renders the buffer as Chrome ``trace_event`` JSON (the ``{"traceEvents":
+[...]}`` object form) that loads in Perfetto / ``chrome://tracing``; the
+health monitor's flight recorder dumps it. ``pid`` is the OS process id
+and ``tid`` a small per-tracer id of the recording OS thread.
 
-A ``None`` tracer is the disabled state: the module-level ``span(tracer,
-name)`` helper yields immediately without reading the clock, so
-uninstrumented runs pay nothing (``benchmarks/obs_bench.py``
-``micro/span`` measures the enabled cost).
+Every span carries the step (or request) it belongs to as an argument, so
+that the spans of one step can be joined across threads.
 """
 from __future__ import annotations
 
 import contextlib
 import json
+import os
 import threading
 import time
 from collections import deque
 from typing import Optional
 
+from jax import profiler as _profiler
+
 REQUIRED_EVENT_KEYS = ("ph", "ts", "dur", "pid", "tid", "name")
+PREFIX = "repro/"          # profiler name of span ``name``: PREFIX + name
 
 
 class Tracer:
@@ -41,15 +44,11 @@ class Tracer:
         self.capacity = int(capacity)
         self._events: deque = deque(maxlen=self.capacity)
         self._lock = threading.Lock()
-        self._t0 = time.perf_counter_ns()
         self._tids: dict = {}
-        self._process_names: dict = {0: "trainer"}
+        self._pid = os.getpid()
         self.dropped = 0
 
     # -- recording ---------------------------------------------------------
-    def _now_us(self) -> float:
-        return (time.perf_counter_ns() - self._t0) / 1e3
-
     def _tid(self) -> int:
         ident = threading.get_ident()
         with self._lock:
@@ -58,44 +57,24 @@ class Tracer:
                 tid = self._tids[ident] = len(self._tids)
             return tid
 
-    def _append(self, event: dict) -> None:
+    def _append(self, ph: str, name: str, t0_ns: int, t1_ns: int,
+                args: dict) -> None:
+        event = {"ph": ph, "name": str(name), "ts": t0_ns / 1e3,
+                 "dur": (t1_ns - t0_ns) / 1e3, "pid": self._pid,
+                 "tid": self._tid()}
+        if ph == "i":
+            event["s"] = "t"
+        if args:
+            event["args"] = {k: _jsonable(v) for k, v in args.items()}
         with self._lock:
             if len(self._events) == self.capacity:
                 self.dropped += 1
             self._events.append(event)
 
-    @contextlib.contextmanager
-    def span(self, name: str, *, pid: int = 0, tid: Optional[int] = None,
-             **args):
-        """Record a complete event named ``name`` around the ``with``
-        body; ``args`` become the event's Perfetto-visible args."""
-        t0 = self._now_us()
-        try:
-            yield self
-        finally:
-            event = {"ph": "X", "name": str(name), "ts": t0,
-                     "dur": self._now_us() - t0, "pid": int(pid),
-                     "tid": self._tid() if tid is None else int(tid)}
-            if args:
-                event["args"] = {k: _jsonable(v) for k, v in args.items()}
-            self._append(event)
-
-    def instant(self, name: str, *, pid: int = 0,
-                tid: Optional[int] = None, **args) -> None:
-        """Record a zero-duration marker (checkpoint published, resume,
-        preemption)."""
-        event = {"ph": "i", "s": "t", "name": str(name),
-                 "ts": self._now_us(), "dur": 0.0, "pid": int(pid),
-                 "tid": self._tid() if tid is None else int(tid)}
-        if args:
-            event["args"] = {k: _jsonable(v) for k, v in args.items()}
-        self._append(event)
-
-    def set_process_name(self, pid: int, name: str) -> None:
-        """Label lane ``pid`` (rendered by Perfetto as the process name —
-        e.g. pid 1+h as ``host h``)."""
-        with self._lock:
-            self._process_names[int(pid)] = str(name)
+    def instant(self, name: str, **args) -> None:
+        """Record a zero-duration marker (anomaly, checkpoint published)."""
+        now = time.time_ns()
+        self._append("i", name, now, now, args)
 
     # -- export ------------------------------------------------------------
     def events(self) -> list:
@@ -104,20 +83,15 @@ class Tracer:
             return [dict(e) for e in self._events]
 
     def to_chrome_trace(self) -> dict:
-        """Chrome ``trace_event`` object form: ``process_name`` metadata
-        records for every named lane, then the buffered events. The
+        """Chrome ``trace_event`` object form of the buffered events. The
         top-level ``metadata`` object reports ``dropped`` (events evicted
         past ``capacity`` — a nonzero value means the timeline is
         truncated at the old end) alongside ``capacity`` and the exported
         event count."""
         with self._lock:
             events = [dict(e) for e in self._events]
-            names = dict(self._process_names)
             dropped = self.dropped
-        meta = [{"ph": "M", "name": "process_name", "pid": pid, "tid": 0,
-                 "ts": 0, "dur": 0, "args": {"name": label}}
-                for pid, label in sorted(names.items())]
-        return {"traceEvents": meta + events, "displayTimeUnit": "ms",
+        return {"traceEvents": events, "displayTimeUnit": "ms",
                 "metadata": {"dropped": dropped, "capacity": self.capacity,
                              "events": len(events)}}
 
@@ -137,12 +111,16 @@ def _jsonable(v):
 
 
 @contextlib.contextmanager
-def span(tracer: Optional[Tracer], name: str, **kw):
-    """``tracer.span(name, **kw)`` when ``tracer`` is a ``Tracer``; a free
-    no-op when it is ``None`` — the one helper hot paths call so disabled
-    tracing costs nothing."""
-    if tracer is None:
-        yield None
-    else:
-        with tracer.span(name, **kw):
+def span(tracer: Optional[Tracer], name: str, **args):
+    """A ``TraceAnnotation`` named ``PREFIX + name`` with ``args`` around
+    the ``with`` body, recorded into ``tracer``'s ring buffer as well when
+    ``tracer`` is a ``Tracer``. Yields ``tracer``."""
+    with _profiler.TraceAnnotation(PREFIX + name, **args):
+        if tracer is None:
+            yield None
+            return
+        t0 = time.time_ns()
+        try:
             yield tracer
+        finally:
+            tracer._append("X", name, t0, time.time_ns(), args)

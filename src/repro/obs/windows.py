@@ -19,9 +19,8 @@ spikes exactly when they matter. Median/MAD have a 50% breakdown point —
 half the window must be outliers before the scale estimate moves — so
 detection stays sharp through the episode (DESIGN.md §14.1).
 
-``push``/``mark`` are a few Python ops under a lock (priced in
-``benchmarks/obs_bench.py`` ``window/observe``); percentile/MAD sort the
-window on demand — the detectors call them once per step on windows of a
+``push``/``mark`` are a few Python ops under a lock; percentile/MAD sort
+the window on demand — the detectors call them once per step on windows of a
 few hundred entries, microseconds of host time.
 """
 from __future__ import annotations

@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import time
 from typing import Optional, Sequence, Union
 
@@ -144,6 +145,7 @@ class ZeroShotService:
 
         self.metrics = obs_metrics.Registry()
         self.tracer = tracer if tracer is not None else obs_trace.Tracer()
+        self._request_ids = itertools.count()   # the spans' ``request``
         enc_i = jax.jit(lambda p, im: de.encode_image(cfg, p, im,
                                                       precision=precision))
         enc_t = jax.jit(lambda p, tx: de.encode_text(cfg, p, tx,
@@ -204,8 +206,9 @@ class ZeroShotService:
         templates = tuple(templates) if templates is not None \
             else self.templates
         t_req = time.perf_counter()
+        rid = next(self._request_ids)
         try:
-            with obs_trace.span(self.tracer, "serve/classify",
+            with obs_trace.span(self.tracer, "serve/classify", request=rid,
                                 n_classes=len(class_names), k=k,
                                 mode=self.retrieval):
                 iemb_fut = self.embed_images(images, wait=False)
@@ -219,7 +222,8 @@ class ZeroShotService:
                 iemb = self._result(iemb_fut)
                 vals, idx = self._topk(iemb, data, len(class_names),
                                        min(k, len(class_names)),
-                                       inv_tau=self.inv_tau, index=index)
+                                       inv_tau=self.inv_tau, index=index,
+                                       request=rid)
         finally:
             if self.slo is not None:
                 self.slo.observe(time.perf_counter() - t_req)
@@ -267,13 +271,15 @@ class ZeroShotService:
                              f"service runs {self.retrieval!r} — call "
                              f"prepare_gallery again")
         t_req = time.perf_counter()
+        rid = next(self._request_ids)
         try:
-            with obs_trace.span(self.tracer, "serve/retrieve",
+            with obs_trace.span(self.tracer, "serve/retrieve", request=rid,
                                 n=handle.n, k=k, mode=self.retrieval):
                 qemb = self.embed_texts(list(queries))
                 return self._topk(qemb, handle.data, handle.n,
                                   min(k, handle.n), inv_tau=1.0,
-                                  index=handle.index, nprobe=nprobe)
+                                  index=handle.index, nprobe=nprobe,
+                                  request=rid)
         finally:
             if self.slo is not None:
                 self.slo.observe(time.perf_counter() - t_req)
@@ -295,7 +301,7 @@ class ZeroShotService:
 
     # -- the top-k sweep ---------------------------------------------------
     def _topk(self, q, data, n: int, k: int, *, inv_tau, index=None,
-              nprobe=None):
+              nprobe=None, request=None):
         """Dispatch the (b, k) sweep per the retrieval mode, recording the
         §13 serving telemetry: per-stage ``serve/retrieval_latency_s``,
         ``serve/retrieval_prune_ratio`` (twostage: candidates/n) and
@@ -303,7 +309,8 @@ class ZeroShotService:
         the winners — 1/S ≈ balanced, →1 ≈ one hot shard)."""
         mode = self.retrieval
         t0 = time.perf_counter()
-        with obs_trace.span(self.tracer, f"serve/topk_{mode}", n=n, k=k):
+        with obs_trace.span(self.tracer, f"serve/topk_{mode}",
+                            request=request, n=n, k=k):
             if mode == "sharded":
                 vals, idx = rtv.sharded_similarity_topk(
                     jnp.asarray(q), data, k, inv_tau=inv_tau,

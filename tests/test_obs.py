@@ -98,9 +98,9 @@ def test_registry_labeled_children_and_snapshot():
 def test_trace_event_schema_and_ring_buffer():
     tr = trace.Tracer(capacity=3)
     for i in range(5):
-        with tr.span("work", pid=i % 2, arg=i):
+        with trace.span(tr, "work", step=i, host=i % 2):
             time.sleep(0.001)
-    tr.instant("marker", pid=0)
+    tr.instant("marker", step=5)
     events = tr.events()
     assert len(events) == 3 and tr.dropped == 3      # ring: newest 3 win
     doc = tr.to_chrome_trace()
@@ -108,36 +108,69 @@ def test_trace_event_schema_and_ring_buffer():
     for ev in doc["traceEvents"]:
         for key in trace.REQUIRED_EVENT_KEYS:
             assert key in ev, (key, ev)
-    # span durations are real wall time
+        assert ev["pid"] == os.getpid()
+    # span durations are real wall time, on the wall clock (µs since epoch)
     spans = [e for e in doc["traceEvents"] if e["ph"] == "X"]
     assert spans and all(e["dur"] >= 900 for e in spans)   # ≥0.9ms in µs
-    # process_name metadata labels the pid lanes
-    metas = [e for e in doc["traceEvents"] if e["ph"] == "M"]
-    assert any(e["args"]["name"] == "trainer" for e in metas)
+    assert all(abs(e["ts"] / 1e6 - time.time()) < 60 for e in spans)
+    # the host rides as an argument of the span, beside its step
+    assert [(e["args"]["step"], e["args"]["host"]) for e in spans] == \
+        [(3, 1), (4, 0)]
 
 
 def test_trace_export_and_none_tracer(tmp_path):
     tr = trace.Tracer()
-    tr.set_process_name(1, "host 0")
-    with tr.span("s"):
-        pass
+    for h in range(2):
+        with trace.span(tr, "data/host_block", step=7, host=h):
+            pass
     path = tr.export(str(tmp_path / "trace.json"))
     doc = json.load(open(path))
-    assert {e["args"]["name"] for e in doc["traceEvents"]
-            if e["ph"] == "M"} >= {"trainer", "host 0"}
+    assert not [e for e in doc["traceEvents"] if e["ph"] == "M"]  # no lanes
+    assert {e["args"]["host"] for e in doc["traceEvents"]
+            if e["name"] == "data/host_block"} == {0, 1}
     with trace.span(None, "noop") as got:            # disabled path
         assert got is None
+
+
+def test_spans_reach_a_profiler_capture(tmp_path):
+    """A span kept in a Tracer and one with no Tracer both appear in a
+    ``jax.profiler`` capture as ``repro/<name>``, with their ``step``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    tr = trace.Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.span(tr, "train/dispatch", step=3):
+            time.sleep(0.002)
+        with trace.span(None, "data/render", step=4):
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    got = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(trace.PREFIX):
+                    got[e.name] = (dict(e.stats).get("step"), e.duration_ns)
+    assert set(got) == {"repro/train/dispatch", "repro/data/render"}
+    assert got["repro/train/dispatch"][0] == 3
+    assert got["repro/data/render"][0] == 4
+    assert all(d >= 1.5e6 for _, d in got.values())
+    assert [e["name"] for e in tr.events()] == ["train/dispatch"]
 
 
 def test_trace_thread_lanes():
     tr = trace.Tracer()
     def work():
-        with tr.span("bg"):
+        with trace.span(tr, "bg"):
             pass
     t = threading.Thread(target=work)
     t.start()
     t.join()
-    with tr.span("fg"):
+    with trace.span(tr, "fg"):
         pass
     tids = {e["name"]: e["tid"] for e in tr.events()}
     assert tids["bg"] != tids["fg"]

@@ -85,11 +85,59 @@ def test_smoke_run_streams_runlog_and_trace(tmp_path, capsys):
 
     doc = json.load(open(os.path.join(rd, "trace.json")))
     spans = [e for e in doc["traceEvents"] if e["ph"] == "X"]
-    assert {"data_wait", "device_step", "ckpt_stall"} <= \
-        {e["name"] for e in spans}
+    assert {"train/data_wait", "train/dispatch", "train/wait",
+            "train/ckpt_stall", "train/log"} <= {e["name"] for e in spans}
+    assert "train/device_step" not in {e["name"] for e in spans}
+    assert all("step" in e["args"] for e in spans)
     for ev in doc["traceEvents"]:
         for key in obs_trace.REQUIRED_EVENT_KEYS:
             assert key in ev, (key, ev)
+
+
+def test_profiled_run_has_loop_and_loader_spans(tmp_path):
+    """Under a ``jax.profiler`` capture, with no run directory (no
+    Tracer), a contrastive smoke run shows one ``repro/train/dispatch``
+    per step on one thread, and each step's ``repro/data/render`` and
+    ``repro/data/put`` (the same ``step``) on another: the prefetch
+    thread's."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    args = types.SimpleNamespace(
+        arch="basic-s", objective="auto", smoke=True, steps=4, batch=8,
+        seq=16, lr=3e-4, seed=0, sharding="basic_ws", remat="basic",
+        model_parallel=1, log_every=100, ckpt_dir=None, ckpt_every=0,
+        stop_after=None, num_micro=2, loss="local", quiet=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        train(args)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    spans = {}              # name -> [(thread row, step)]
+    row = 0
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("repro/"):
+                    spans.setdefault(e.name, []).append(
+                        (row, dict(e.stats)["step"]))
+            row += 1
+    dispatch = spans["repro/train/dispatch"]
+    assert sorted(s for _, s in dispatch) == list(range(4))
+    main = {t for t, _ in dispatch}
+    assert len(main) == 1
+    for name in ("repro/train/data_wait", "repro/train/wait",
+                 "repro/train/log"):
+        assert sorted(spans[name]) == sorted(dispatch), name
+    render, put = spans["repro/data/render"], spans["repro/data/put"]
+    assert sorted(render) == sorted(put)     # each render, then its put
+    rows = {t for t, _ in render}
+    assert len(rows) == 1 and not rows & main
+    assert set(range(4)) <= {s for _, s in render}
+    blocks = spans["repro/data/host_block"]
+    assert sorted(blocks) == sorted(render)  # one data shard: one block
 
 
 def test_resume_appends_to_runlog_with_marker(tmp_path):
