@@ -13,6 +13,8 @@ from typing import Callable, Iterator
 
 import numpy as np
 
+from repro.obs import trace as obs_trace
+
 
 class Prefetcher:
     """Wrap a batch-producing callable into a prefetching iterator.
@@ -23,7 +25,11 @@ class Prefetcher:
     waits with a timed get so a consumer blocked on an empty queue wakes
     up and terminates — after ``close()``, or when the worker died —
     instead of hanging forever (the historical deadlock); a worker killed
-    by a ``make_batch`` exception re-raises it at the consumer."""
+    by a ``make_batch`` exception re-raises it at the consumer.
+
+    ``make_batch(step)`` runs once per step, in step order; the worker's
+    wait for room in the queue is a ``repro/data/queue_wait`` span (with
+    ``step``), long where the consumer sets the pace."""
 
     def __init__(self, make_batch: Callable[[int], object], depth: int = 2,
                  start: int = 0):
@@ -40,13 +46,25 @@ class Prefetcher:
         step = self._start
         try:
             while not self._stop.is_set():
-                try:
-                    self._q.put(self._make(step), timeout=0.5)
-                    step += 1
-                except queue.Full:
-                    continue
+                item = self._make(step)
+                with obs_trace.span(None, "data/queue_wait", step=step):
+                    if not self._offer(item):
+                        return
+                step += 1
         except BaseException as e:  # noqa: BLE001 — surfaced in __next__
             self._error = e
+
+    def _offer(self, item) -> bool:
+        """Put ``item`` once the queue has room: True, or False when
+        ``close()`` came first. The timed put keeps the worker responsive
+        to ``close()``; a step is made once, however long it waits."""
+        while not self._stop.is_set():
+            try:
+                self._q.put(item, timeout=0.5)
+                return True
+            except queue.Full:
+                continue
+        return False
 
     def __iter__(self) -> Iterator:
         return self

@@ -176,11 +176,13 @@ class ShardedLoader:
     def global_batch_at(self, step: int) -> dict:
         """The full global batch of step ``step``: every host's block,
         concatenated in host order (the single-process materialization and
-        the oracle the two-host test reassembles against)."""
+        the oracle the two-host test reassembles against). With one host
+        that is its block itself, not a copy."""
         import jax
         t0 = time.perf_counter()
         blocks = [self._block(step, h) for h in range(self.layout.n_hosts)]
-        out = jax.tree.map(lambda *xs: np.concatenate(xs, axis=0), *blocks)
+        out = blocks[0] if len(blocks) == 1 else jax.tree.map(
+            lambda *xs: np.concatenate(xs, axis=0), *blocks)
         if self._h_global is not None:
             self._h_global.observe(time.perf_counter() - t0)
         return out

@@ -92,18 +92,36 @@ def world_for_tower(rng: np.random.Generator, tower, n_classes=64,
                       channels=tower.channels, noise=noise)
 
 
+# float64 camera product per chunk of render_images: small enough to stay
+# in cache, large enough that each gemm is worth waking the BLAS threads
+_RENDER_CHUNK_BYTES = 1 << 24
+
+
 def render_images(world: World, cls: np.ndarray, rng: np.random.Generator):
     """cls: (b,) int -> RAW images (b, H, W, C) float32: per-patch noisy
-    concept latents through the camera map, assembled on the patch grid."""
+    concept latents through the camera map, assembled on the patch grid.
+
+    One pass: the float64 camera product runs as one 2-D gemm per chunk of
+    images (about ``_RENDER_CHUNK_BYTES`` of float64 product each), and its
+    float32 cast lands straight in the output through the patch-grid view.
+    The result is a pure function of ``(world, cls, rng)``, byte-identical
+    to the stacked ``(z @ camera).astype(float32)`` then transpose."""
     b = cls.shape[0]
     g = world.image_size // world.patch_size
     ps, c = world.patch_size, world.channels
-    z = world.concept_vecs[cls]                                  # (b, k)
-    z = z[:, None, :] + world.noise * rng.standard_normal(
-        (b, world.n_patches, z.shape[-1]))
-    pix = (z @ world.camera).astype(np.float32)   # (b, P, ps*ps*C)
-    pix = pix.reshape(b, g, g, ps, ps, c).transpose(0, 1, 3, 2, 4, 5)
-    return np.ascontiguousarray(pix.reshape(b, g * ps, g * ps, c))
+    p, k = world.n_patches, world.concept_vecs.shape[-1]
+    z = rng.standard_normal((b, p, k))
+    z *= world.noise
+    z += world.concept_vecs[cls][:, None, :]
+    out = np.empty((b, g * ps, g * ps, c), np.float32)
+    grid = out.reshape(b, g, ps, g, ps, c)
+    step = max(1, _RENDER_CHUNK_BYTES // (8 * p * world.camera.shape[-1]))
+    for i in range(0, b, step):
+        n = min(step, b - i)
+        pix = z[i:i + n].reshape(n * p, k) @ world.camera
+        grid[i:i + n] = pix.reshape(n, g, g, ps, ps, c).transpose(
+            0, 1, 3, 2, 4, 5)
+    return out
 
 
 def render_captions(world: World, cls: np.ndarray, rng: np.random.Generator,

@@ -58,8 +58,9 @@ degrade / resume marker records, and exports a Chrome ``trace_event``
 JSON (``trace.json``, Perfetto-viewable) on exit. Each step also runs
 under ``jax.profiler.StepTraceAnnotation("train")`` with its phases as
 ``repro/train/*`` spans (data_wait, dispatch, wait, ckpt_stall, log) and
-the prefetch thread's ``repro/data/render`` / ``repro/data/put``, so any
-profiler capture lines them up with the device's ops.
+the prefetch thread's ``repro/data/render`` / ``repro/data/put`` /
+``repro/data/queue_wait``, so any profiler capture lines them up with the
+device's ops.
 ``--log-every N`` paces the human stdout line, ``--quiet`` silences it;
 summarize a run with ``python -m repro.obs.report <run-dir>/runlog.jsonl``.
 

@@ -3,11 +3,17 @@
 Hypothesis-based tokenizer fuzzing lives in test_data_properties.py (behind
 ``importorskip``) so this module collects on bare environments.
 """
-import numpy as np
+import threading
+import time
 
-from repro.data import (Tokenizer, caption_corpus, classification_prompts,
-                        contrastive_batch, host_rng, make_world)
+import numpy as np
+import pytest
+
+from repro.data import (HostLayout, ShardedLoader, Tokenizer, caption_corpus,
+                        classification_prompts, contrastive_batch, host_rng,
+                        make_world)
 from repro.data.pipeline import Prefetcher
+from repro.data.synthetic import render_images
 
 
 _CACHE = {}
@@ -180,3 +186,91 @@ def test_prefetcher_surfaces_worker_crash():
     with np.testing.assert_raises(ValueError):
         next(pf)
     pf.close()                    # still idempotent after a crash
+
+
+def _stacked_render(world, cls, rng):
+    """The stacked formula render_images must match byte for byte: a 3-D
+    ``z @ camera``, its float32 cast, then the patch-grid transpose."""
+    b = cls.shape[0]
+    g = world.image_size // world.patch_size
+    ps, c = world.patch_size, world.channels
+    z = world.concept_vecs[cls]
+    z = z[:, None, :] + world.noise * rng.standard_normal(
+        (b, world.n_patches, z.shape[-1]))
+    pix = (z @ world.camera).astype(np.float32)
+    pix = pix.reshape(b, g, g, ps, ps, c).transpose(0, 1, 3, 2, 4, 5)
+    return np.ascontiguousarray(pix.reshape(b, g * ps, g * ps, c))
+
+
+@pytest.mark.parametrize("batch", [1, 17])
+@pytest.mark.parametrize("image_size,patch_size,channels",
+                         [(16, 4, 3), (32, 8, 3), (224, 16, 3), (28, 4, 1)])
+def test_render_images_byte_identical_to_stacked_formula(
+        image_size, patch_size, channels, batch):
+    """The one-pass render (2-D gemm per chunk, cast and transpose into the
+    output; 17 images of 224² span two chunks) gives the stacked formula's
+    bytes and leaves the rng where the stacked formula leaves it."""
+    world = make_world(np.random.default_rng(image_size), n_classes=16,
+                       image_size=image_size, patch_size=patch_size,
+                       channels=channels)
+    cls = np.random.default_rng(batch).integers(0, 16, batch)
+    rng_new, rng_old = np.random.default_rng(5), np.random.default_rng(5)
+    got = render_images(world, cls, rng_new)
+    want = _stacked_render(world, cls, rng_old)
+    assert got.dtype == np.float32 and got.flags.c_contiguous
+    assert got.shape == want.shape == (batch, image_size, image_size,
+                                       channels)
+    assert got.tobytes() == want.tobytes()
+    assert rng_new.standard_normal() == rng_old.standard_normal()
+
+
+def test_global_batch_at_one_host_is_local_batch():
+    world, tok = _tok()
+    loader = ShardedLoader(world, tok, 12, layout=HostLayout(1), seed=4)
+    for step in (0, 3):
+        got, want = loader.global_batch_at(step), loader.local_batch_at(step)
+        assert got["images"]["image"].tobytes() == \
+            want["images"]["image"].tobytes()
+        for key in ("tokens", "attn_mask"):
+            assert got["texts"][key].tobytes() == want["texts"][key].tobytes()
+
+
+def test_prefetcher_makes_each_step_once_under_a_slow_consumer():
+    """Regression: the worker used to call make_batch(step) again each
+    time its put on the full queue timed out (0.5 s), so a consumer slower
+    than that saw every step made over and over."""
+    calls = []
+
+    def make(step):
+        calls.append(step)
+        return step
+
+    pf = Prefetcher(make, depth=1)
+    got = []
+    for _ in range(3):
+        got.append(next(pf))
+        time.sleep(0.7)               # longer than the put's timeout
+    got.append(next(pf))
+    pf.close()
+    assert got == [0, 1, 2, 3]
+    assert calls == list(range(len(calls)))
+
+
+def test_prefetcher_close_during_pending_put_ends_stream():
+    made = threading.Event()
+
+    def make(step):
+        if step == 1:
+            made.set()                # the queue (depth 1) holds step 0
+        return step
+
+    pf = Prefetcher(make, depth=1)
+    assert made.wait(timeout=5.0)
+    time.sleep(0.1)                   # the worker is inside its put
+    t0 = time.time()
+    pf.close()
+    assert time.time() - t0 < 2.0
+    assert not pf._thread.is_alive()
+    assert list(pf) == [0]
+    with pytest.raises(StopIteration):
+        next(pf)

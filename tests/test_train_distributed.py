@@ -97,9 +97,9 @@ def test_smoke_run_streams_runlog_and_trace(tmp_path, capsys):
 def test_profiled_run_has_loop_and_loader_spans(tmp_path):
     """Under a ``jax.profiler`` capture, with no run directory (no
     Tracer), a contrastive smoke run shows one ``repro/train/dispatch``
-    per step on one thread, and each step's ``repro/data/render`` and
-    ``repro/data/put`` (the same ``step``) on another: the prefetch
-    thread's."""
+    per step on one thread, and each step's ``repro/data/render``,
+    ``repro/data/put`` and ``repro/data/queue_wait`` (the same ``step``,
+    each step once) on another: the prefetch thread's."""
     import glob
 
     import jax
@@ -133,6 +133,8 @@ def test_profiled_run_has_loop_and_loader_spans(tmp_path):
         assert sorted(spans[name]) == sorted(dispatch), name
     render, put = spans["repro/data/render"], spans["repro/data/put"]
     assert sorted(render) == sorted(put)     # each render, then its put
+    assert sorted(spans["repro/data/queue_wait"]) == sorted(render)
+    assert len({s for _, s in render}) == len(render)   # each step once
     rows = {t for t, _ in render}
     assert len(rows) == 1 and not rows & main
     assert set(range(4)) <= {s for _, s in render}
